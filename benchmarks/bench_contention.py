@@ -13,7 +13,8 @@ measured abort rate and mix profile — the cluster never changes, so the
 curve isolates contention.
 
 Run as a script the mix goes through the per-type mesh executors on a
-simulated multi-server deployment (``--shards``, forced host devices);
+multi-server deployment (``--shards``: the first N devices of the default
+backend, forced host devices under ``JAX_PLATFORMS=cpu``);
 ``run()`` keeps the single-shard reference path for ``benchmarks/run.py``
 (no mesh leakage into the shared process).
 
@@ -25,7 +26,6 @@ import argparse
 import time
 
 import jax
-import numpy as np
 
 from repro import compat
 from repro.core import netmodel
@@ -67,8 +67,7 @@ def measure(alpha, *, n_shards: int = 0, n_rounds: int = 6,
     if n_shards:
         oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=n_shards)
         lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(0))
-        mesh = jax.sharding.Mesh(np.array(compat.cpu_devices()[:n_shards]),
-                                 ("mem",))
+        mesh = compat.mesh(n_shards)
         engine = tpcc.make_mixed_engine(cfg, lay, mesh, "mem", oracle,
                                         shard_vector=True)
         st = tpcc.distribute_state(engine, st)

@@ -11,12 +11,13 @@ calibrated InfiniBand model fed with the attempt-share-weighted mix profile
 
 ``--shards N`` (default 8) additionally sweeps the shard count 1→N running
 the mixed rounds through ``store.distributed_round`` (write types) and
-``store.distributed_readonly_round`` (read-only types) on a simulated
-N-memory-server mesh (forced host devices), in both Fig. 5 deployments:
-locality-aware (warehouse-major placement + home routing) and
-locality-oblivious (table-major placement + round-robin thread pinning). The
-script re-execs itself with ``XLA_FLAGS=--xla_force_host_platform_device_count``
-when the host does not expose enough devices.
+``store.distributed_readonly_round`` (read-only types) on an
+N-memory-server mesh over the first N devices of the default backend, in
+both Fig. 5 deployments: locality-aware (warehouse-major placement + home
+routing) and locality-oblivious (table-major placement + round-robin thread
+pinning). Under ``JAX_PLATFORMS=cpu`` the script re-execs itself once with
+``XLA_FLAGS=--xla_force_host_platform_device_count`` when the flag does not
+already force enough host devices.
 
 ``--sustain N`` switches to the §5.3 sustained-execution bench: N new-order
 rounds at a FIXED shard count through the mesh executors with the GC thread
@@ -146,8 +147,7 @@ def measure_sharded(n_shards: int, mode: str, n_rounds: int = 8,
                           dist_degree=dist_degree, layout=layout)
     oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=n_shards)
     lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(0))
-    mesh = jax.sharding.Mesh(np.array(compat.cpu_devices()[:n_shards]),
-                             ("mem",))
+    mesh = compat.mesh(n_shards)
     engine = tpcc.make_mixed_engine(cfg, lay, mesh, "mem", oracle,
                                     shard_vector=True)
     st = tpcc.distribute_state(engine, st)
@@ -236,8 +236,7 @@ def run_sustain(n_rounds: int, n_shards: int, n_threads: int, *,
         n_overflow=n_overflow, layout=layout)
     oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=n_shards)
     lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(0))
-    mesh = jax.sharding.Mesh(np.array(compat.cpu_devices()[:n_shards]),
-                             ("mem",))
+    mesh = compat.mesh(n_shards)
     engine = tpcc.make_distributed_engine(cfg, lay, mesh, "mem", oracle,
                                           shard_vector=True)
     st = tpcc.distribute_state(engine, st)
@@ -364,8 +363,7 @@ def run_recovery(n_rounds: int, n_shards: int, n_threads: int, *,
     def journalled_run(failure):
         oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=n_shards)
         lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(0))
-        mesh = jax.sharding.Mesh(np.array(compat.cpu_devices()[:n_shards]),
-                                 ("mem",))
+        mesh = compat.mesh(n_shards)
         engine = tpcc.make_mixed_engine(cfg, lay, mesh, "mem", oracle,
                                         shard_vector=True, with_journal=True)
         st = tpcc.distribute_state(engine, st)
@@ -487,8 +485,7 @@ def run_expand(n_rounds: int, old_shards: int, new_shards: int,
     def journalled_run(n_shards, growth):
         oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=n_shards)
         lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(0))
-        mesh = jax.sharding.Mesh(np.array(compat.cpu_devices()[:n_shards]),
-                                 ("mem",))
+        mesh = compat.mesh(n_shards)
         engine = tpcc.make_mixed_engine(cfg, lay, mesh, "mem", oracle,
                                         shard_vector=True, with_journal=True)
         st = tpcc.distribute_state(engine, st)

@@ -15,6 +15,9 @@ import sys
 def main() -> None:
     from benchmarks import (bench_contention, bench_locality, bench_oracle,
                             bench_tpcc_scaling)
+    from repro import compat
+
+    compat.use_compile_cache()
 
     print("name,us_per_call,derived")
 

@@ -11,15 +11,16 @@ new-order** txn/s, the paper's Fig. 4 split (6.5M new-order of 14.5M total).
     PYTHONPATH=src python examples/tpcc_demo.py --rounds 8 --skew 0.9
 
 With ``--shards 8`` the rounds run through ``store.distributed_round`` (and
-``store.distributed_readonly_round`` for the read-only types) on a simulated
-8-memory-server mesh (forced host devices; the script re-execs itself to set
-XLA_FLAGS), in both Fig. 5 locality deployments.
+``store.distributed_readonly_round`` for the read-only types) on an
+8-memory-server mesh, in both Fig. 5 locality deployments. The memory
+servers are the first 8 devices of the default backend: TPU chips on a TPU
+host; under ``JAX_PLATFORMS=cpu`` the script re-execs itself once with
+XLA_FLAGS forcing 8 host devices.
 """
 import argparse
 import time
 
 import jax
-import numpy as np
 
 from repro import compat
 from repro.core import locality, netmodel
@@ -53,8 +54,7 @@ def run_sharded(args):
             dist_degree=args.dist, skew_alpha=args.skew, layout=layout)
         oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=args.shards)
         lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(0))
-        mesh = jax.sharding.Mesh(np.array(compat.cpu_devices()[:args.shards]),
-                                 ("mem",))
+        mesh = compat.mesh(args.shards)
         engine = tpcc.make_mixed_engine(cfg, lay, mesh, "mem", oracle,
                                         shard_vector=True)
         st = tpcc.distribute_state(engine, st)
@@ -89,6 +89,7 @@ def main():
                     help="run through distributed_round on this many "
                     "simulated memory servers")
     args = ap.parse_args()
+    compat.use_compile_cache()
 
     if args.shards > 1:
         compat.ensure_host_devices(args.shards)

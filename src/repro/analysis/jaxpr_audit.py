@@ -40,6 +40,7 @@ import numpy as np
 from jax._src import source_info_util
 from jax.extend import core as jex_core
 
+from repro import compat
 from repro.analysis.rules import Finding, apply_suppressions
 from repro.core import annotations as anno
 from repro.core import gc as gc_ops
@@ -316,8 +317,6 @@ def _trace_run_round() -> ClosedJaxpr:
 
 
 def _trace_distributed_round() -> ClosedJaxpr:
-    from jax.sharding import Mesh
-
     # 5 threads over 2 shards: a non-dividing vector, so the pad_vector
     # path is part of the audited surface. Falls back to a 1-shard mesh on
     # a single device — the body jaxpr (tags, collectives, journal appends)
@@ -325,7 +324,7 @@ def _trace_distributed_round() -> ClosedJaxpr:
     n_shards = 2 if len(jax.devices()) >= 2 else 1
     oracle, table, state, batch, journal = _fixture(n_threads=5)
     n_records = table.cur_hdr.shape[0]
-    mesh = Mesh(np.array(jax.devices()[:n_shards]), ("shard",))
+    mesh = compat.mesh(n_shards, "shard")
     round_fn, _ = store.distributed_round(
         mesh, "shard", oracle,
         lambda rh, rd, v, aux: rd[:, :batch.write_ref.shape[1], :] + 1,

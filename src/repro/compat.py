@@ -1,77 +1,86 @@
-"""Version compatibility shims for JAX.
+"""Devices, meshes and the compile cache: one place for what depends on the
+host the engine runs on.
 
-``shard_map`` moved twice across JAX releases:
-
-* jax <= 0.4.x: ``jax.experimental.shard_map.shard_map`` with a ``check_rep``
-  keyword,
-* jax >= 0.5:   re-exported as ``jax.shard_map`` with ``check_rep`` renamed
-  to ``check_vma``.
-
-Every module in this repo imports :func:`shard_map` from here so the
-difference is papered over in exactly one place. The shim presents the NEW
-interface (``check_vma``) and translates for old installs.
+* :func:`mesh` — the memory-server mesh over the devices that exist: the
+  chips on a TPU host, forced host devices in a CPU run.
+* :func:`ensure_host_devices` — gives a CPU run (``JAX_PLATFORMS=cpu``) the
+  host devices a script's mesh needs; does nothing anywhere else.
+* :func:`use_compile_cache` — JAX's persistent compilation cache, placed by
+  ``JAX_COMPILATION_CACHE_DIR`` or at a fixed path in the checkout.
 """
 from __future__ import annotations
 
-import functools
+import os
+import re
+import sys
 
 import jax
+from jax.sharding import AxisType, Mesh
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.5
-    shard_map = jax.shard_map
-else:                                               # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    @functools.wraps(_legacy_shard_map)
-    def shard_map(f=None, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if f is None:                               # partial-application form
-            return functools.partial(shard_map, **kwargs)
-        return _legacy_shard_map(f, **kwargs)
+# <checkout>/.jax_cache: fixed, so the cache key (which includes the path)
+# stays the same from run to run; listed in .gitignore
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (jax >= 0.5); on older jax the size of a mapped
-    axis is recovered with a constant-folded ``psum(1)``."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+def mesh(n: int, axis: str = "mem") -> Mesh:
+    """A 1-D mesh over the first ``n`` devices of the default backend.
 
-
-def cpu_devices():
-    """CPU devices only — simulated memory-server meshes live on these.
-    Counting ``jax.devices()`` instead would never grow from the forced-
-    host-device flag on a GPU/TPU host (default backend wins)."""
-    try:
-        return jax.devices("cpu")
-    except RuntimeError:
-        return []
-
-
-def ensure_host_devices(n: int, *, marker: str = "_REPRO_MESH_REEXEC"):
-    """Guarantee ``n`` forced CPU host devices for a CLI script.
-
-    XLA reads ``--xla_force_host_platform_device_count`` only before jax
-    initializes, so a script that needs a simulated mesh re-execs itself
-    once with the flag set. ``marker`` prevents an exec loop when the flag
-    cannot take effect (e.g. overridden XLA_FLAGS). No-op when enough CPU
-    devices already exist.
+    Each device is one memory server. The axis is ``Auto``: under the
+    ``Explicit`` axes that ``jax.make_mesh`` gives by default, gathers on
+    arrays sharded over the axis raise ``ShardingTypeError``. Raises if the
+    backend has fewer than ``n`` devices.
     """
-    import os
-    import sys
+    devices = jax.devices()
+    if len(devices) < n:
+        raise RuntimeError(
+            f"a {n}-device mesh needs {n} devices; the "
+            f"{jax.default_backend()} backend has {len(devices)} (a CPU run "
+            f"gets more with JAX_PLATFORMS=cpu and "
+            f"compat.ensure_host_devices, or XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n})")
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=devices[:n])
 
-    if len(cpu_devices()) >= n:
+
+def ensure_host_devices(n: int) -> None:
+    """Give a CPU run ``n`` host devices for a script's simulated mesh.
+
+    Decided from the environment alone, without starting a backend: only
+    under ``JAX_PLATFORMS=cpu``, and only when ``XLA_FLAGS`` does not
+    already force ``n`` or more host devices, does the script re-exec
+    itself with the flag added (XLA reads it once, when the CPU backend
+    starts). No accelerator has been touched in such a run. On any other
+    platform it does nothing: the mesh takes the accelerator's devices and
+    :func:`mesh` raises if there are too few.
+    """
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
         return
-    if os.environ.get(marker):
-        raise SystemExit(
-            f"still only {len(cpu_devices())} CPU devices after re-exec "
-            f"(wanted {n}); is XLA_FLAGS being overridden?")
-    flag = f"--xla_force_host_platform_device_count={n}"
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
-    env[marker] = "1"
+    flags = os.environ.get("XLA_FLAGS", "")
+    forced = re.findall(r"--xla_force_host_platform_device_count=(\d+)",
+                        flags)
+    if forced and int(forced[-1]) >= n:
+        return
+    env = dict(os.environ, XLA_FLAGS=(
+        flags + f" --xla_force_host_platform_device_count={n}").strip())
     os.execve(sys.executable, [sys.executable] + sys.argv, env)
 
 
-__all__ = ["shard_map", "axis_size", "cpu_devices", "ensure_host_devices"]
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX uses that directory and
+    nothing else is set here. Otherwise the cache goes to
+    :data:`DEFAULT_CACHE_DIR`. Call it from a script's ``main``, before the
+    first compile.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+__all__ = ["DEFAULT_CACHE_DIR", "mesh", "ensure_host_devices",
+           "use_compile_cache"]

@@ -21,9 +21,8 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.core import annotations as anno
 from repro.core import cas, gc as gc_ops, hashtable as ht, header as hdr_ops, \

@@ -23,6 +23,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.checkpoint import snapshot
 
 from repro.core import cas, gc as gc_ops, hashtable as ht, \
@@ -1271,7 +1272,7 @@ def scale_out(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
         engine.n_dir_buckets, old_n, new_n))) if engine.n_dir_buckets else 0
 
     # ---- 3. cutover: re-place onto the grown mesh, rebuild executors -----
-    new_mesh = jax.make_mesh((new_n,), (engine.axis,))
+    new_mesh = compat.mesh(new_n, engine.axis)
     if isinstance(engine, MixedEngine):
         new_engine = make_mixed_engine(
             cfg, lay, new_mesh, engine.axis, oracle,
@@ -1300,6 +1301,55 @@ def scale_out(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
 
 
 # ----------------------------------------------------- mixed-round driver ----
+def _sub_rounds(cfg: TPCCConfig, lay: TPCCLayout, oracle: VectorOracle,
+                engine: Optional[MixedEngine], last_n: int) -> dict:
+    """Compile each transaction type's sub-round as one program: a dict from
+    type name to a jitted ``(st, inp, round_no, active, journal)`` for the
+    write types and ``(st, inp, active)`` for the read-only ones.
+
+    Run op by op, the first round of a mix compiles every primitive of every
+    type on its own: about 800 small programs at any table size, each a
+    separate compile on a TPU. As one program per type, the first round
+    compiles five and later rounds reuse them: the round number and the
+    active mask are traced. On a mesh each type compiles once more, in the
+    round after its first, because the retry merge then hands in inputs
+    replicated over the mesh in place of the first round's single-device
+    draws. Each program carries its type's round function name, which is
+    the name JAX's compile events report."""
+    def jit_as(fn, name):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
+
+    def write(single, dist):
+        if engine is None:
+            def run(st, inp, round_no, active, journal):
+                return single(cfg, lay, st, oracle, inp, round_no=round_no,
+                              active=active, journal=journal)
+        else:
+            def run(st, inp, round_no, active, journal):
+                return dist(cfg, lay, st, oracle, engine, inp,
+                            round_no=round_no, active=active,
+                            journal=journal)
+        return jit_as(run, single.__name__)
+
+    def orderstatus(st, inp, active):
+        return orderstatus_round(cfg, lay, st, oracle, inp, engine=engine,
+                                 active=active)
+
+    def stocklevel(st, inp, active):
+        return stocklevel_round(cfg, lay, st, oracle, inp, engine=engine,
+                                active=active, last_n=last_n)
+
+    return {"neworder": write(neworder_round, neworder_round_distributed),
+            "payment": write(payment_round, payment_round_distributed),
+            "delivery": write(delivery_round, delivery_round_distributed),
+            "orderstatus": jit_as(orderstatus, "orderstatus_round"),
+            "stocklevel": jit_as(stocklevel, "stocklevel_round")}
+
+
+_version_mover = jax.jit(mvcc.version_mover, static_argnames=("reuse_only",))
+
+
 class MixedRunStats(NamedTuple):
     """Aggregates of a full five-transaction-mix run (§7: the paper's total
     throughput only exists because the whole 45/43/4/4/4 mix runs
@@ -1428,6 +1478,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
     if jnl is not None and checkpoint_dir is not None:
         snapshot.save(checkpoint_dir, _mem_state(st, jnl),
                       extra={"round": -1})
+    programs = _sub_rounds(cfg, lay, oracle, engine, stock_last_n)
 
     def acc_ops(name, ops):
         for i, f in enumerate(ops):
@@ -1472,6 +1523,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
                 cfg, lay, st, oracle, engine, jnl, checkpoint_dir, growth,
                 use_gc=use_gc, move_versions=move_versions, gc_log=gc_log)
             placement = engine.placement
+            programs = _sub_rounds(cfg, lay, oracle, engine, stock_last_n)
             growth_reports.append(grep)
             # the retry queues ride across the cut untouched in content, but
             # their arrays are committed to the old mesh — re-land them
@@ -1494,14 +1546,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
         # masked sub-round would be a pure no-op contributing zero stats)
         act = ttype == 0
         if int(jnp.sum(act)):
-            if engine is None:
-                out = neworder_round(cfg, lay, st, oracle, inp.neworder,
-                                     round_no=r, active=act, journal=jnl)
-            else:
-                out = neworder_round_distributed(cfg, lay, st, oracle,
-                                                 engine, inp.neworder,
-                                                 round_no=r, active=act,
-                                                 journal=jnl)
+            out = programs["neworder"](st, inp.neworder, r, act, jnl)
             st, jnl = out.state, out.journal
             aborted_round |= acc_write("neworder", act, out.committed,
                                        out.ops, out.snapshot_miss, out.vis)
@@ -1510,13 +1555,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
 
         act = ttype == 1
         if int(jnp.sum(act)):
-            if engine is None:
-                pay = payment_round(cfg, lay, st, oracle, inp.payment,
-                                    active=act, round_no=r, journal=jnl)
-            else:
-                pay = payment_round_distributed(cfg, lay, st, oracle, engine,
-                                                inp.payment, active=act,
-                                                round_no=r, journal=jnl)
+            pay = programs["payment"](st, inp.payment, r, act, jnl)
             st, jnl = pay.state, pay.journal
             aborted_round |= acc_write("payment", act, pay.committed,
                                        pay.ops, pay.snapshot_miss, pay.vis)
@@ -1525,13 +1564,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
 
         act = ttype == 3
         if int(jnp.sum(act)):
-            if engine is None:
-                dl = delivery_round(cfg, lay, st, oracle, inp.delivery,
-                                    active=act, round_no=r, journal=jnl)
-            else:
-                dl = delivery_round_distributed(cfg, lay, st, oracle, engine,
-                                                inp.delivery, active=act,
-                                                round_no=r, journal=jnl)
+            dl = programs["delivery"](st, inp.delivery, r, act, jnl)
             st, jnl = dl.state, dl.journal
             aborted_round |= acc_write("delivery", act, dl.committed, dl.ops,
                                        dl.snapshot_miss, dl.vis)
@@ -1543,8 +1576,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
         act = ttype == 2
         n_act = int(jnp.sum(act))
         if n_act:
-            ro = orderstatus_round(cfg, lay, st, oracle, inp.orderstatus,
-                                   engine=engine, active=act)
+            ro = programs["orderstatus"](st, inp.orderstatus, act)
             attempts["orderstatus"] += n_act
             commits["orderstatus"] += n_act
             acc_ops("orderstatus", ro.ops)
@@ -1554,9 +1586,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
         act = ttype == 4
         n_act = int(jnp.sum(act))
         if n_act:
-            sl = stocklevel_round(cfg, lay, st, oracle, inp.stocklevel,
-                                  engine=engine, active=act,
-                                  last_n=stock_last_n)
+            sl = programs["stocklevel"](st, inp.stocklevel, act)
             attempts["stocklevel"] += n_act
             commits["stocklevel"] += n_act
             acc_ops("stocklevel", sl.ops)
@@ -1567,7 +1597,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
         pending = inp
         if move_versions:
             st = st._replace(nam=st.nam._replace(
-                table=mvcc.version_mover(st.nam.table, reuse_only=use_gc)))
+                table=_version_mover(st.nam.table, reuse_only=use_gc)))
         if use_gc and (r + 1) % gc_interval == 0:
             st, gc_log, frac = _gc_sweep(lay, st, engine, gc_log, r,
                                          max_txn_time)

@@ -22,6 +22,7 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro import policy as perf
 from repro.checkpoint import snapshot
@@ -37,7 +38,8 @@ from repro.train.trainstep import make_train_step
 def make_mesh(kind: str):
     if kind == "host":            # whatever this host offers (CPU: 1)
         n = len(jax.devices())
-        return jax.make_mesh((n, 1), ("data", "model"))
+        return jax.make_mesh((n, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     return make_production_mesh(multi_pod=(kind == "multipod"))
 
 

@@ -13,8 +13,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 

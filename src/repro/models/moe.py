@@ -14,9 +14,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
-from repro.compat import shard_map
+from jax import shard_map
 
 
 def init_moe(key, d_model: int, d_ff: int, n_experts: int, dtype):
@@ -172,7 +170,7 @@ def apply_moe_sharded(params, x, *, top_k: int, capacity_factor: float,
         # with a *scatter* over D, combine on D-shards, and all-gather the
         # carry-sized y — ~1.4x fewer wire bytes than psum([E,C,D]) and the
         # combine gathers move D/|model| slices instead of full rows.
-        nm = compat.axis_size("model")
+        nm = jax.lax.axis_size("model")
         out = jax.lax.psum_scatter(out.astype(xl.dtype), "model",
                                    scatter_dimension=2, tiled=True)
         yl = jnp.zeros((Tl, D // nm), jnp.float32)        # local D slice

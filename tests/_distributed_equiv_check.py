@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.core import locality
 from repro.core.tsoracle import PartitionedVectorOracle, VectorOracle
 from repro.db import tpcc, workload
@@ -225,7 +226,7 @@ def check_key_equals_slot(layout, lay, slot_run, key_run):
 
 def main():
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("mem",))
+    mesh = compat.mesh(8)
     for layout in ("table_major", "warehouse_major"):
         cfg, lay, single, dist = run_neworder(layout, mesh)
         run_payment_delivery(layout, cfg, lay, single, dist)

@@ -13,13 +13,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.core import mvcc, si, store
 from repro.core.tsoracle import VectorOracle
 
 
 def main():
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("mem",))
+    mesh = compat.mesh(8)
     n_records, width, n_threads = 64, 4, 16
     shard_records = n_records // 8
     oracle = VectorOracle(n_threads)

@@ -30,6 +30,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import numpy as np
 
+from repro import compat
 from repro.core import locality, store
 from repro.core.tsoracle import PartitionedVectorOracle
 from repro.db import tpcc, workload
@@ -43,7 +44,7 @@ GC = dict(gc_interval=2, max_txn_time=1)
 
 def setup(cfg, n_shards):
     """A freshly loaded ``n_shards``-way deployment with journalling."""
-    mesh = jax.make_mesh((n_shards,), ("mem",))
+    mesh = compat.mesh(n_shards)
     oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=n_shards)
     lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(0))
     engine = tpcc.make_mixed_engine(cfg, lay, mesh, "mem", oracle,

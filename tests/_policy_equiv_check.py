@@ -17,12 +17,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro import policy
 from repro.models import common
 from repro.models import moe as moe_mod
 
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 
 
 def check_embed():

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.core import locality, store
 from repro.core.tsoracle import PartitionedVectorOracle
 from repro.db import tpcc, workload
@@ -123,7 +124,7 @@ def run_layout(layout, mesh):
 
 def main():
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("mem",))
+    mesh = compat.mesh(8)
     for layout in ("table_major", "warehouse_major"):
         run_layout(layout, mesh)
     print("RECOVERY_EQUIV_OK")

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.checkpoint import snapshot
 from repro.train import optimizer as opt
 
@@ -63,7 +64,7 @@ def test_elastic_restore_new_sharding(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     params = _tree(jax.random.PRNGKey(3))
     snapshot.save(str(tmp_path), params, step=3)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = compat.mesh(1, "data")
     shard_tree = jax.tree.map(
         lambda x: NamedSharding(mesh, P(*([None] * x.ndim))), params)
     p2, _, _ = snapshot.restore(str(tmp_path), params,

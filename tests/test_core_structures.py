@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import catalog as cat, hashtable as ht, locality, netmodel
 from repro.core import rangeindex as ri
 from repro.core import store as store_mod
@@ -276,7 +277,7 @@ def test_shard_vector_non_dividing_round_matches_reference():
     With zero-padding the partitioned vector must stay bit-identical to the
     single-shard reference on the real slots."""
     from repro.core import mvcc, si
-    mesh = jax.make_mesh((2,), ("mem",))
+    mesh = compat.mesh(2)
     n_records, width, n_threads = 16, 4, 3      # 3 slots over 2 shards
     oracle = VectorOracle(n_threads)
 

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.core import gc, header as hdr, mvcc, si
 from repro.core.tsoracle import VectorOracle
 
@@ -354,7 +355,7 @@ def _mesh_mix(seed, n_shards, growth):
     cfg = tpcc.TPCCConfig(n_warehouses=4, customers_per_district=8,
                           n_items=64, n_threads=6, orders_per_thread=16,
                           dist_degree=30.0)
-    mesh = jax.make_mesh((n_shards,), ("mem",))
+    mesh = compat.mesh(n_shards)
     oracle = PartitionedVectorOracle(cfg.n_threads, n_parts=n_shards)
     lay, st0 = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(1))
     engine = tpcc.make_mixed_engine(cfg, lay, mesh, "mem", oracle,

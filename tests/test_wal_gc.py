@@ -1,8 +1,9 @@
 """Tests: WAL journaling, recovery replay, abandoned-lock release, GC —
 including the §5.3 sustained-execution pieces (snapshot-ring wraparound,
 reclaimed-slot version moving, lazy truncation, and the per-shard mesh
-sweep, which runs whenever the process sees ≥2 CPU devices, e.g. under CI's
+sweep, which runs whenever the process sees ≥2 devices, e.g. under CI's
 8-forced-host-device step)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -330,17 +331,15 @@ def test_compact_overflow_resets_deleted_slots_only():
         np.testing.assert_array_equal(np.asarray(a.data), np.asarray(b.data))
 
 
-@pytest.mark.skipif(len(compat.cpu_devices()) < 2,
-                    reason="needs ≥2 CPU devices (run under the CI mesh "
+@pytest.mark.skipif(len(jax.devices()) < 2,
+                    reason="needs ≥2 devices (run under the CI mesh "
                     "step's forced host devices)")
 def test_distributed_gc_round_matches_single_shard():
     """The per-shard mesh sweep (store.distributed_gc_round) must be
     bit-identical to gc.gc_round over the whole pool, with every shard's
     snapshot log agreeing with the single-shard one."""
-    import jax
-
-    n = 2 if len(compat.cpu_devices()) < 4 else 4
-    mesh = jax.sharding.Mesh(np.array(compat.cpu_devices()[:n]), ("mem",))
+    n = 2 if len(jax.devices()) < 4 else 4
+    mesh = compat.mesh(n)
     n_records, width, T = 8 * n, 2, 3
     tbl_s = mvcc.init_table(n_records, width, n_old=1, n_overflow=4)
     o = VectorOracle(T)
