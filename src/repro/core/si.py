@@ -317,67 +317,74 @@ def run_round(
     if active is None:
         active = jnp.ones((T,), bool)
 
-    # ---- 1. read timestamp (whole vector = the snapshot) -----------------
-    if rts_vec is None:
-        rts_vec = oracle.read(state)
+    with jax.named_scope("si.read"):
+        # ---- 1. read timestamp (whole vector = the snapshot) -------------
+        if rts_vec is None:
+            rts_vec = oracle.read(state)
 
-    # ---- 2. key resolution (§5.2) + visible reads -------------------------
-    flat_slots = batch.read_slots.reshape(-1)
-    if batched_probe:
-        # one kernel launch resolves every lane of the read-set: directory
-        # probe for the key-addressed lanes, §5.1 version location for all —
-        # then exactly one payload gather outside (DESIGN.md §8)
-        from repro.kernels.hash_probe import ops as probe_ops
-        if directory is not None:
-            assert keyed is not None, "key-addressed mode needs KeyedReads"
-            slot_out, f_out, src, pos = probe_ops.batched_probe(
-                directory.keys, directory.vals, table, rts_vec, flat_slots,
-                keyed.keys.reshape(-1), keyed.mask.reshape(-1),
-                max_probes=dir_max_probes)
-            n_index_probes = jnp.sum(keyed.mask & batch.read_mask
-                                     & active[:, None])
+        # ---- 2. key resolution (§5.2) + visible reads ---------------------
+        flat_slots = batch.read_slots.reshape(-1)
+        if batched_probe:
+            # one kernel launch resolves every lane of the read-set:
+            # directory probe for the key-addressed lanes, §5.1 version
+            # location for all — then exactly one payload gather outside
+            # (DESIGN.md §8)
+            from repro.kernels.hash_probe import ops as probe_ops
+            if directory is not None:
+                assert keyed is not None, \
+                    "key-addressed mode needs KeyedReads"
+                slot_out, f_out, src, pos = probe_ops.batched_probe(
+                    directory.keys, directory.vals, table, rts_vec,
+                    flat_slots,
+                    keyed.keys.reshape(-1), keyed.mask.reshape(-1),
+                    max_probes=dir_max_probes)
+                n_index_probes = jnp.sum(keyed.mask & batch.read_mask
+                                         & active[:, None])
+            else:
+                slot_out, f_out, src, pos = probe_ops.batched_probe(
+                    None, None, table, rts_vec, flat_slots, None, None)
+                n_index_probes = 0
+            # a keyed miss reports slot -1; gather at the safe slot 0,
+            # exactly like the unfused path below — never a negative-slot
+            # gather
+            flat_slots = jnp.where(slot_out >= 0, slot_out, 0)
+            read_slots = flat_slots.reshape(T, RS)
+            hdr_flat, data_flat = mvcc.gather_version(
+                table, flat_slots,
+                mvcc.VersionLoc(found=f_out, src=src, pos=pos))
+            read_hdr = hdr_flat.reshape(T, RS, 2)
+            read_data = data_flat.reshape(T, RS, W)
+            read_found = f_out.reshape(T, RS)
+            from_current = (f_out & (src == mvcc.SRC_CURRENT)).reshape(T,
+                                                                       RS)
+            from_ovf = (f_out & (src == mvcc.SRC_OVF)).reshape(T, RS)
         else:
-            slot_out, f_out, src, pos = probe_ops.batched_probe(
-                None, None, table, rts_vec, flat_slots, None, None)
-            n_index_probes = 0
-        # a keyed miss reports slot -1; gather at the safe slot 0, exactly
-        # like the unfused path below — never a negative-slot gather
-        flat_slots = jnp.where(slot_out >= 0, slot_out, 0)
-        read_slots = flat_slots.reshape(T, RS)
-        hdr_flat, data_flat = mvcc.gather_version(
-            table, flat_slots,
-            mvcc.VersionLoc(found=f_out, src=src, pos=pos))
-        read_hdr = hdr_flat.reshape(T, RS, 2)
-        read_data = data_flat.reshape(T, RS, W)
-        read_found = f_out.reshape(T, RS)
-        from_current = (f_out & (src == mvcc.SRC_CURRENT)).reshape(T, RS)
-        from_ovf = (f_out & (src == mvcc.SRC_OVF)).reshape(T, RS)
-    else:
-        if directory is not None:
-            assert keyed is not None, "key-addressed mode needs KeyedReads"
-            kvals, kfound = ht.lookup(directory, keyed.keys.reshape(-1),
-                                      max_probes=dir_max_probes)
-            km = keyed.mask.reshape(-1)
-            flat_slots = jnp.where(km, jnp.where(kfound, kvals, 0),
-                                   flat_slots)
-            key_ok = ~km | kfound
-            n_index_probes = jnp.sum(keyed.mask & batch.read_mask
-                                     & active[:, None])
-        else:
-            key_ok = jnp.ones(flat_slots.shape, bool)
-            n_index_probes = 0
-        read_slots = flat_slots.reshape(T, RS)  # resolved slots, used below
-        vr = mvcc.read_visible(table, flat_slots, rts_vec)
-        read_hdr = vr.hdr.reshape(T, RS, 2)
-        read_data = vr.data.reshape(T, RS, W)
-        # a directory miss resolves to the safe slot 0 — mask its visibility
-        # outcomes wholesale so the miss is not telemetried (or op-counted)
-        # as a served read of record 0
-        read_found = (vr.found & key_ok).reshape(T, RS)
-        from_current = (vr.from_current & key_ok).reshape(T, RS)
-        from_ovf = (vr.from_ovf & key_ok).reshape(T, RS)
-    found = read_found | ~batch.read_mask
-    txn_found = jnp.all(found, axis=1)
+            if directory is not None:
+                assert keyed is not None, \
+                    "key-addressed mode needs KeyedReads"
+                kvals, kfound = ht.lookup(directory, keyed.keys.reshape(-1),
+                                          max_probes=dir_max_probes)
+                km = keyed.mask.reshape(-1)
+                flat_slots = jnp.where(km, jnp.where(kfound, kvals, 0),
+                                       flat_slots)
+                key_ok = ~km | kfound
+                n_index_probes = jnp.sum(keyed.mask & batch.read_mask
+                                         & active[:, None])
+            else:
+                key_ok = jnp.ones(flat_slots.shape, bool)
+                n_index_probes = 0
+            read_slots = flat_slots.reshape(T, RS)  # resolved, used below
+            vr = mvcc.read_visible(table, flat_slots, rts_vec)
+            read_hdr = vr.hdr.reshape(T, RS, 2)
+            read_data = vr.data.reshape(T, RS, W)
+            # a directory miss resolves to the safe slot 0 — mask its
+            # visibility outcomes wholesale so the miss is not telemetried
+            # (or op-counted) as a served read of record 0
+            read_found = (vr.found & key_ok).reshape(T, RS)
+            from_current = (vr.from_current & key_ok).reshape(T, RS)
+            from_ovf = (vr.from_ovf & key_ok).reshape(T, RS)
+        found = read_found | ~batch.read_mask
+        txn_found = jnp.all(found, axis=1)
 
     # ---- 3. transaction logic (local to the compute server) --------------
     new_data = compute_fn(read_hdr, read_data, rts_vec)
@@ -422,32 +429,37 @@ def run_round(
             round_no=journal_round, seq=journal_seq)
 
     # ---- 5./7./8./9. validate+lock, install, release, make visible --------
-    std_vis = type(oracle).make_visible is VectorOracle.make_visible
-    if fused_commit:
-        from repro.kernels.commit import ops as commit_ops
-        fc = commit_ops.fused_commit(
-            table, state.vec, req_slots, req_expected, req_prio, req_active,
-            txn_of_req, new_hdr.reshape(-1, 2), new_data.reshape(-1, W),
-            txn_ok, oracle.slot_of_thread(batch.tid), cts,
-            jnp.zeros((T,), jnp.int32))
-        table = fc.table
-        granted = anno.tag(fc.granted, anno.LOCK_GRANTED)
-        committed = anno.tag(fc.committed, anno.COMMIT_COMMITTED)
-        do_install = fc.do_install
-        release_mask = anno.tag(granted & ~committed[txn_of_req],
-                                anno.LOCK_RELEASED)
-        if std_vis:   # the kernel's in-launch make-visible IS the vector
-            state = state._replace(vec=fc.vec)   # oracle's scatter-max
-        else:         # custom oracle machinery — run it, drop kernel's vec
+    with jax.named_scope("si.commit"):
+        std_vis = type(oracle).make_visible is VectorOracle.make_visible
+        if fused_commit:
+            from repro.kernels.commit import ops as commit_ops
+            fc = commit_ops.fused_commit(
+                table, state.vec, req_slots, req_expected, req_prio,
+                req_active, txn_of_req, new_hdr.reshape(-1, 2),
+                new_data.reshape(-1, W), txn_ok,
+                oracle.slot_of_thread(batch.tid), cts,
+                jnp.zeros((T,), jnp.int32))
+            table = fc.table
+            granted = anno.tag(fc.granted, anno.LOCK_GRANTED)
+            committed = anno.tag(fc.committed, anno.COMMIT_COMMITTED)
+            do_install = fc.do_install
+            release_mask = anno.tag(granted & ~committed[txn_of_req],
+                                    anno.LOCK_RELEASED)
+            if std_vis:   # the kernel's in-launch make-visible IS the
+                #           vector oracle's scatter-max
+                state = state._replace(vec=fc.vec)
+            else:         # custom oracle machinery: run it, drop the vec
+                state = oracle.make_visible(state, batch.tid, cts,
+                                            committed)
+        else:
+            co = commit_write_sets(table, req_slots, req_expected,
+                                   req_prio, req_active, txn_of_req,
+                                   new_hdr.reshape(-1, 2),
+                                   new_data.reshape(-1, W), txn_ok)
+            table = co.table
+            granted, committed = co.granted, co.committed
+            do_install, release_mask = co.do_install, co.release_mask
             state = oracle.make_visible(state, batch.tid, cts, committed)
-    else:
-        co = commit_write_sets(table, req_slots, req_expected, req_prio,
-                               req_active, txn_of_req, new_hdr.reshape(-1, 2),
-                               new_data.reshape(-1, W), txn_ok)
-        table = co.table
-        granted, committed = co.granted, co.committed
-        do_install, release_mask = co.do_install, co.release_mask
-        state = oracle.make_visible(state, batch.tid, cts, committed)
 
     # the outcome record lands after the decision (§3.2: until it does the
     # transaction is undetermined and its locks are the monitor's)

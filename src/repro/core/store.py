@@ -267,74 +267,76 @@ def distributed_round(mesh: Mesh, axis: str, oracle: VectorOracle,
         WS = batch.write_ref.shape[1]
         W = table.payload_width
 
-        # ---- 1. read the timestamp vector (gather the partitions) --------
-        if shard_vector:
-            vec = jax.lax.all_gather(vec, axis, tiled=True)
-            if padded_slots != oracle.n_slots:
-                vec = vec[:oracle.n_slots]
+        with jax.named_scope("si.read"):
+            # ---- 1. read the timestamp vector (gather the partitions) -------
+            if shard_vector:
+                vec = jax.lax.all_gather(vec, axis, tiled=True)
+                if padded_slots != oracle.n_slots:
+                    vec = vec[:oracle.n_slots]
 
-        # ---- 2a. key resolution against the partitioned directory (§5.2) -
-        if n_dir_buckets:
-            dir_keys, dir_vals, read_keys, key_mask = dir_args
-            dir_base = shard_id * (n_dir_buckets // n_shards)
-            vsum, khit = ht.lookup_shard(
-                dir_keys, dir_vals, read_keys.reshape(-1), dir_base,
-                n_dir_buckets, max_probes=dir_max_probes)
-            vsum = jax.lax.psum(vsum, axis)
-            khit = jax.lax.psum(khit.astype(jnp.int32), axis) > 0
-            kfound = khit & (vsum >= 0)
-            km = key_mask.reshape(-1)
-            flat = jnp.where(km, jnp.where(kfound, vsum, 0),
-                             batch.read_slots.reshape(-1))
-            key_ok = ~km | kfound
-        else:
-            flat = batch.read_slots.reshape(-1)
-            key_ok = jnp.ones(flat.shape, bool)
-        read_slots = flat.reshape(T, RS)     # resolved slots, used below
+            # ---- 2a. key resolution against the partitioned directory (§5.2)
+            if n_dir_buckets:
+                dir_keys, dir_vals, read_keys, key_mask = dir_args
+                dir_base = shard_id * (n_dir_buckets // n_shards)
+                vsum, khit = ht.lookup_shard(
+                    dir_keys, dir_vals, read_keys.reshape(-1), dir_base,
+                    n_dir_buckets, max_probes=dir_max_probes)
+                vsum = jax.lax.psum(vsum, axis)
+                khit = jax.lax.psum(khit.astype(jnp.int32), axis) > 0
+                kfound = khit & (vsum >= 0)
+                km = key_mask.reshape(-1)
+                flat = jnp.where(km, jnp.where(kfound, vsum, 0),
+                                 batch.read_slots.reshape(-1))
+                key_ok = ~km | kfound
+            else:
+                flat = batch.read_slots.reshape(-1)
+                key_ok = jnp.ones(flat.shape, bool)
+            read_slots = flat.reshape(T, RS)     # resolved slots, used below
 
-        # ---- 2b. one-sided visible reads (masked local + all-reduce) -----
-        loc, inside = _local_slots(flat, base, shard_records)
-        safe = jnp.where(inside, loc, 0)
-        if batched_probe:
-            # batched-probe kernel in locate-only mode: each memory server
-            # resolves its masked local slots in ONE launch, then a single
-            # payload gather (DESIGN.md §8). Key resolution stays the
-            # partitioned lookup_shard + psum above — the bucket array is
-            # range-partitioned, so no single shard can walk a whole probe
-            # sequence. gather_version over the kernel's locator reproduces
-            # read_visible bit-exactly (the lock-step-oracle contract), so
-            # the psum/masking combine below is untouched.
-            from repro.kernels.hash_probe import ops as probe_ops
-            _, f_loc, src, pos = probe_ops.batched_probe(
-                None, None, table, vec, safe, None, None)
-            hdr_f, data_f = mvcc.gather_version(
-                table, safe, mvcc.VersionLoc(found=f_loc, src=src, pos=pos))
-            vr = mvcc.VisibleRead(
-                hdr=hdr_f, data=data_f, found=f_loc,
-                from_current=f_loc & (src == mvcc.SRC_CURRENT),
-                from_ovf=f_loc & (src == mvcc.SRC_OVF))
-        else:
-            vr = mvcc.read_visible(table, safe, vec)
-        rh = jnp.where(inside[:, None], vr.hdr, 0)
-        rd = jnp.where(inside[:, None], vr.data, 0)
-        fnd = jnp.where(inside, vr.found, False)
-        fcur = jnp.where(inside, vr.from_current, False)
-        fovf = jnp.where(inside, vr.from_ovf, False)
-        rh = jax.lax.psum(rh, axis)
-        rd = jax.lax.psum(rd, axis)
-        # key_ok masks a directory miss's visibility outcomes wholesale
-        # (the miss resolved to the safe slot 0) — identically to
-        # si.run_round, so the two paths' telemetry cannot diverge
-        read_found = ((jax.lax.psum(fnd.astype(jnp.int32), axis) > 0)
-                      & key_ok).reshape(T, RS)
-        from_current = ((jax.lax.psum(fcur.astype(jnp.int32), axis) > 0)
+            # ---- 2b. one-sided visible reads (masked local + all-reduce) ----
+            loc, inside = _local_slots(flat, base, shard_records)
+            safe = jnp.where(inside, loc, 0)
+            if batched_probe:
+                # batched-probe kernel in locate-only mode: each memory server
+                # resolves its masked local slots in ONE launch, then a single
+                # payload gather (DESIGN.md §8). Key resolution stays the
+                # partitioned lookup_shard + psum above — the bucket array is
+                # range-partitioned, so no single shard can walk a whole probe
+                # sequence. gather_version over the kernel's locator reproduces
+                # read_visible bit-exactly (the lock-step-oracle contract), so
+                # the psum/masking combine below is untouched.
+                from repro.kernels.hash_probe import ops as probe_ops
+                _, f_loc, src, pos = probe_ops.batched_probe(
+                    None, None, table, vec, safe, None, None)
+                hdr_f, data_f = mvcc.gather_version(
+                    table, safe,
+                    mvcc.VersionLoc(found=f_loc, src=src, pos=pos))
+                vr = mvcc.VisibleRead(
+                    hdr=hdr_f, data=data_f, found=f_loc,
+                    from_current=f_loc & (src == mvcc.SRC_CURRENT),
+                    from_ovf=f_loc & (src == mvcc.SRC_OVF))
+            else:
+                vr = mvcc.read_visible(table, safe, vec)
+            rh = jnp.where(inside[:, None], vr.hdr, 0)
+            rd = jnp.where(inside[:, None], vr.data, 0)
+            fnd = jnp.where(inside, vr.found, False)
+            fcur = jnp.where(inside, vr.from_current, False)
+            fovf = jnp.where(inside, vr.from_ovf, False)
+            rh = jax.lax.psum(rh, axis)
+            rd = jax.lax.psum(rd, axis)
+            # key_ok masks a directory miss's visibility outcomes wholesale
+            # (the miss resolved to the safe slot 0) — identically to
+            # si.run_round, so the two paths' telemetry cannot diverge
+            read_found = ((jax.lax.psum(fnd.astype(jnp.int32), axis) > 0)
+                          & key_ok).reshape(T, RS)
+            from_current = ((jax.lax.psum(fcur.astype(jnp.int32), axis) > 0)
+                            & key_ok).reshape(T, RS)
+            from_ovf = ((jax.lax.psum(fovf.astype(jnp.int32), axis) > 0)
                         & key_ok).reshape(T, RS)
-        from_ovf = ((jax.lax.psum(fovf.astype(jnp.int32), axis) > 0)
-                    & key_ok).reshape(T, RS)
-        read_hdr = rh.reshape(T, RS, 2).astype(jnp.uint32)
-        read_data = rd.reshape(T, RS, W)
-        found = read_found | ~batch.read_mask
-        txn_found = jnp.all(found, axis=1)
+            read_hdr = rh.reshape(T, RS, 2).astype(jnp.uint32)
+            read_data = rd.reshape(T, RS, W)
+            found = read_found | ~batch.read_mask
+            txn_found = jnp.all(found, axis=1)
 
         # ---- 3. local transaction logic (replicated, deterministic) ------
         new_data = compute_fn(read_hdr, read_data, vec, aux)
@@ -378,82 +380,87 @@ def distributed_round(mesh: Mesh, axis: str, oracle: VectorOracle,
                                 new_data, req_active.reshape(T, WS)),
                 round_no=jround, seq=jseq)
 
-        std_vis = type(oracle).make_visible is VectorOracle.make_visible
-        if fused_commit:
-            # ---- 5.-9. fused: the decide/apply double-launch (§8) --------
-            # the same pure kernel runs twice per shard: a decide pass with
-            # ext_fails = 0 whose only used output is this shard's
-            # per-transaction failure counts (the psum is the global AND of
-            # phase 6), then the apply pass replays the identical
-            # tournament with ext_fails = total - local and writes the net
-            # state transition — bit-equal to the unfused arbitrate → psum
-            # → install → release rendering in the else-branch.
-            from repro.kernels.commit import ops as commit_ops
-            lslots = jnp.where(winside, wloc, 0)
-            dec = commit_ops.fused_commit(
-                table, vec, lslots, expected.reshape(-1, 2), prio, mine,
-                txn_of_req, new_hdr.reshape(-1, 2), new_data.reshape(-1, W),
-                txn_found & active, slot_ids, cts,
-                jnp.zeros((T,), jnp.int32))
-            ext_fails = jax.lax.psum(dec.fails, axis) - dec.fails
-            fc = commit_ops.fused_commit(
-                table, vec, lslots, expected.reshape(-1, 2), prio, mine,
-                txn_of_req, new_hdr.reshape(-1, 2), new_data.reshape(-1, W),
-                txn_found & active, slot_ids, cts, ext_fails)
-            table = fc.table
-            granted = anno.tag(fc.granted, anno.LOCK_GRANTED)
-            committed = anno.tag(fc.committed, anno.COMMIT_COMMITTED)
-            do_install = fc.do_install
-            release_mask = anno.tag(granted & ~committed[txn_of_req],
-                                    anno.LOCK_RELEASED)
-        else:
-            # ---- 5. validate+lock on the owning shard --------------------
-            res = cas.arbitrate(table.cur_hdr, jnp.where(winside, wloc, 0),
-                                expected.reshape(-1, 2), prio, mine)
-            granted = anno.tag(res.granted, anno.LOCK_GRANTED)
-            table = table._replace(cur_hdr=res.new_hdr)
+        with jax.named_scope("si.commit"):
+            std_vis = type(oracle).make_visible is VectorOracle.make_visible
+            if fused_commit:
+                # ---- 5.-9. fused: the decide/apply double-launch (§8) -------
+                # the same pure kernel runs twice per shard: a decide pass with
+                # ext_fails = 0 whose only used output is this shard's
+                # per-transaction failure counts (the psum is the global AND of
+                # phase 6), then the apply pass replays the identical
+                # tournament with ext_fails = total - local and writes the net
+                # state transition — bit-equal to the unfused arbitrate → psum
+                # → install → release rendering in the else-branch.
+                from repro.kernels.commit import ops as commit_ops
+                lslots = jnp.where(winside, wloc, 0)
+                dec = commit_ops.fused_commit(
+                    table, vec, lslots, expected.reshape(-1, 2), prio, mine,
+                    txn_of_req, new_hdr.reshape(-1, 2),
+                    new_data.reshape(-1, W),
+                    txn_found & active, slot_ids, cts,
+                    jnp.zeros((T,), jnp.int32))
+                ext_fails = jax.lax.psum(dec.fails, axis) - dec.fails
+                fc = commit_ops.fused_commit(
+                    table, vec, lslots, expected.reshape(-1, 2), prio, mine,
+                    txn_of_req, new_hdr.reshape(-1, 2),
+                    new_data.reshape(-1, W),
+                    txn_found & active, slot_ids, cts, ext_fails)
+                table = fc.table
+                granted = anno.tag(fc.granted, anno.LOCK_GRANTED)
+                committed = anno.tag(fc.committed, anno.COMMIT_COMMITTED)
+                do_install = fc.do_install
+                release_mask = anno.tag(granted & ~committed[txn_of_req],
+                                        anno.LOCK_RELEASED)
+            else:
+                # ---- 5. validate+lock on the owning shard -------------------
+                res = cas.arbitrate(table.cur_hdr, jnp.where(winside, wloc, 0),
+                                    expected.reshape(-1, 2), prio, mine)
+                granted = anno.tag(res.granted, anno.LOCK_GRANTED)
+                table = table._replace(cur_hdr=res.new_hdr)
 
-            K = table.n_old
-            vpos = jnp.mod(table.next_write[jnp.where(mine, wloc, 0)], K)
-            victim = table.old_hdr[jnp.where(mine, wloc, 0), vpos]
-            effective = granted & hdr_ops.is_moved(victim)
+                K = table.n_old
+                vpos = jnp.mod(table.next_write[jnp.where(mine, wloc, 0)], K)
+                victim = table.old_hdr[jnp.where(mine, wloc, 0), vpos]
+                effective = granted & hdr_ops.is_moved(victim)
 
-            # ---- 6. global commit decision (psum of failures) ------------
-            failed_local = mine & ~effective
-            fails = jnp.zeros((T,), jnp.int32).at[txn_of_req].add(
-                failed_local.astype(jnp.int32))
-            fails = jax.lax.psum(fails, axis)
-            committed = anno.tag((fails == 0) & txn_found & active,
-                                 anno.COMMIT_COMMITTED)
+                # ---- 6. global commit decision (psum of failures) -----------
+                failed_local = mine & ~effective
+                fails = jnp.zeros((T,), jnp.int32).at[txn_of_req].add(
+                    failed_local.astype(jnp.int32))
+                fails = jax.lax.psum(fails, axis)
+                committed = anno.tag((fails == 0) & txn_found & active,
+                                     anno.COMMIT_COMMITTED)
 
-            # ---- 7./8. install / release on the owning shard -------------
-            do_install = effective & committed[txn_of_req]
-            inst = mvcc.install(table, wloc, new_hdr.reshape(-1, 2),
-                                new_data.reshape(-1, W), do_install)
-            table = inst.table
-            release_mask = anno.tag(granted & ~committed[txn_of_req],
-                                    anno.LOCK_RELEASED)
-            table = table._replace(
-                cur_hdr=cas.release(table.cur_hdr, wloc, release_mask))
-        n_installs = jax.lax.psum(jnp.sum(do_install.astype(jnp.int32)), axis)
-        n_releases = jax.lax.psum(jnp.sum(release_mask.astype(jnp.int32)),
-                                  axis)
+                # ---- 7./8. install / release on the owning shard ------------
+                do_install = effective & committed[txn_of_req]
+                inst = mvcc.install(table, wloc, new_hdr.reshape(-1, 2),
+                                    new_data.reshape(-1, W), do_install)
+                table = inst.table
+                release_mask = anno.tag(granted & ~committed[txn_of_req],
+                                        anno.LOCK_RELEASED)
+                table = table._replace(
+                    cur_hdr=cas.release(table.cur_hdr, wloc, release_mask))
+            n_installs = jax.lax.psum(jnp.sum(do_install.astype(jnp.int32)),
+                                      axis)
+            n_releases = jax.lax.psum(jnp.sum(release_mask.astype(jnp.int32)),
+                                      axis)
 
         # ---- 9. make visible (identical update as the reference path) ----
         if with_journal:   # outcome record after the global decision (§3.2)
             journal = wal.append_outcome(journal, batch.tid, committed)
-        if fused_commit and std_vis:
-            vec = fc.vec   # the kernel's in-launch scatter-max (phase 9)
-        else:
-            vec = oracle.make_visible(
-                VectorState(vec=vec), batch.tid, cts, committed).vec
-        if shard_vector:
-            if padded_slots != oracle.n_slots:
-                vec = jnp.concatenate(
-                    [vec, jnp.zeros((padded_slots - oracle.n_slots,),
-                                    vec.dtype)])
-            vec = jax.lax.dynamic_slice_in_dim(
-                vec, shard_id * part_slots, part_slots)
+        with jax.named_scope("si.commit"):
+            if fused_commit and std_vis:
+                vec = fc.vec   # the kernel's in-launch scatter-max (phase 9)
+            else:
+                vec = oracle.make_visible(
+                    VectorState(vec=vec), batch.tid, cts, committed).vec
+            if shard_vector:
+                if padded_slots != oracle.n_slots:
+                    vec = jnp.concatenate(
+                        [vec, jnp.zeros((padded_slots - oracle.n_slots,),
+                                        vec.dtype)])
+                vec = jax.lax.dynamic_slice_in_dim(
+                    vec, shard_id * part_slots, part_slots)
 
         out = DistRoundOut(
             committed=committed, snapshot_miss=~txn_found,
@@ -545,6 +552,7 @@ def distributed_readonly_round(mesh: Mesh, axis: str, shard_records: int, *,
         raise ValueError(f"n_dir_buckets ({n_dir_buckets}) must divide over "
                          f"the mesh axis ({n_shards})")
 
+    @jax.named_scope("si.read")
     def local_read(table: VersionedTable, vec: jnp.ndarray, read_slots,
                    read_mask, *dir_args):
         shard_id = jax.lax.axis_index(axis)

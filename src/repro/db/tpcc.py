@@ -1349,6 +1349,11 @@ def _sub_rounds(cfg: TPCCConfig, lay: TPCCLayout, oracle: VectorOracle,
 
 _version_mover = jax.jit(mvcc.version_mover, static_argnames=("reuse_only",))
 
+# The driver's host spans: ``jax.profiler`` records them on the device
+# trace's clock while a trace runs, and they cost about a microsecond each
+# when none does.
+_span = jax.profiler.TraceAnnotation
+
 
 class MixedRunStats(NamedTuple):
     """Aggregates of a full five-transaction-mix run (§7: the paper's total
@@ -1375,6 +1380,8 @@ class MixedRunStats(NamedTuple):
     #                                 injected memory-server failure
     growth: tuple = ()              # (ScaleOutReport, …) — one per online
     #                                 mesh expansion (DESIGN.md §4.3)
+    host_reads: int = 0             # blocking device-to-host reads of the
+    #                                 round loop and the closing retry count
 
 
 def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
@@ -1427,62 +1434,82 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
     mesh; reports ride on ``MixedRunStats.growth``. ``skew`` applies the
     zipfian warehouse/district/remote-payment knobs of
     :class:`repro.db.workload.Skew` to every drawn transaction.
+
+    Under ``jax.profiler`` the host's work shows as spans: ``tpcc.start``
+    (entry to round 0), one ``tpcc.round`` per round (argument ``round``)
+    holding ``tpcc.draw`` (draw and retry merge), ``tpcc.gate`` (each type's
+    lane count) and ``tpcc.tally`` (the statistics after a program), then
+    ``tpcc.finish``; each blocking device-to-host read is a ``tpcc.read``
+    inside them, and ``MixedRunStats.host_reads`` counts those reads.
     """
-    T = cfg.n_threads
-    _check_layout_homes(cfg, lay, home_w, locality_mode)
-    if logits is None:
-        logits = workload.zipf_logits(cfg.n_items, cfg.skew_alpha)
-    if dist_degree is None:
-        dist_degree = cfg.dist_degree
-    placement = engine.placement if engine is not None else \
-        locality.Placement(n_servers=1,
-                           shard_records=lay.catalog.total_records)
-    names = workload.TXN_TYPES
-    attempts = {n: 0 for n in names}
-    commits = {n: 0 for n in names}
-    retries = {n: 0 for n in names}
-    ops_sum = {n: [0.0] * len(si.OpCounts._fields) for n in names}
-    snapshot_misses = {n: 0 for n in names}
-    contention_aborts = {n: 0 for n in names}
-    ovf_reads = {n: 0 for n in names}
-    use_gc = gc_interval > 0
-    gc_log = _gc_init(oracle, engine, gc_interval, gc_snapshots)
-    gc_sweeps = ovf_peak = 0
-    reclaim_traj = []
-    delivered = 0
-    lf_local = lf_total = 0.0
-    tids = jnp.arange(T, dtype=jnp.int32)
-    pending_type = jnp.full((T,), -1, jnp.int32)
-    pending: Optional[workload.MixedInputs] = None
-    jnl = journal
-    recovery = []
-    if failure is not None and (jnl is None or checkpoint_dir is None):
-        raise ValueError("failure injection needs a journal and a "
-                         "checkpoint_dir: §6.2 recovery replays the "
-                         "surviving journals onto the last checkpoint")
-    if jnl is not None and engine is not None and not engine.with_journal:
-        raise ValueError("journaling through the mesh needs an engine "
-                         "built with with_journal=True")
-    growth_reports = []
-    if growth is not None:
-        if engine is None or jnl is None or checkpoint_dir is None:
-            raise ValueError("online scale-out needs a mesh engine, a "
-                             "journal and a checkpoint_dir: §4.3 migration "
-                             "replays the journal onto the last checkpoint")
-        if not 0 <= growth.grow_round < n_rounds:
-            raise ValueError(f"grow_round {growth.grow_round} outside the "
-                             f"{n_rounds}-round run")
-        if growth.new_shards <= engine.n_shards:
-            raise ValueError(f"new_shards ({growth.new_shards}) must exceed "
-                             f"the current mesh ({engine.n_shards})")
-    if jnl is not None and checkpoint_dir is not None:
-        snapshot.save(checkpoint_dir, _mem_state(st, jnl),
-                      extra={"round": -1})
-    programs = _sub_rounds(cfg, lay, oracle, engine, stock_last_n)
+    with _span("tpcc.start"):
+        T = cfg.n_threads
+        _check_layout_homes(cfg, lay, home_w, locality_mode)
+        if logits is None:
+            logits = workload.zipf_logits(cfg.n_items, cfg.skew_alpha)
+        if dist_degree is None:
+            dist_degree = cfg.dist_degree
+        placement = engine.placement if engine is not None else \
+            locality.Placement(n_servers=1,
+                               shard_records=lay.catalog.total_records)
+        names = workload.TXN_TYPES
+        attempts = {n: 0 for n in names}
+        commits = {n: 0 for n in names}
+        retries = {n: 0 for n in names}
+        ops_sum = {n: [0.0] * len(si.OpCounts._fields) for n in names}
+        snapshot_misses = {n: 0 for n in names}
+        contention_aborts = {n: 0 for n in names}
+        ovf_reads = {n: 0 for n in names}
+        use_gc = gc_interval > 0
+        gc_log = _gc_init(oracle, engine, gc_interval, gc_snapshots)
+        gc_sweeps = ovf_peak = host_reads = 0
+        reclaim_traj = []
+        delivered = 0
+        lf_local = lf_total = 0.0
+        tids = jnp.arange(T, dtype=jnp.int32)
+        pending_type = jnp.full((T,), -1, jnp.int32)
+        pending: Optional[workload.MixedInputs] = None
+        jnl = journal
+        recovery = []
+        if failure is not None and (jnl is None or checkpoint_dir is None):
+            raise ValueError("failure injection needs a journal and a "
+                             "checkpoint_dir: §6.2 recovery replays the "
+                             "surviving journals onto the last checkpoint")
+        if jnl is not None and engine is not None and \
+                not engine.with_journal:
+            raise ValueError("journaling through the mesh needs an engine "
+                             "built with with_journal=True")
+        growth_reports = []
+        if growth is not None:
+            if engine is None or jnl is None or checkpoint_dir is None:
+                raise ValueError("online scale-out needs a mesh engine, a "
+                                 "journal and a checkpoint_dir: §4.3 "
+                                 "migration replays the journal onto the "
+                                 "last checkpoint")
+            if not 0 <= growth.grow_round < n_rounds:
+                raise ValueError(f"grow_round {growth.grow_round} outside "
+                                 f"the {n_rounds}-round run")
+            if growth.new_shards <= engine.n_shards:
+                raise ValueError(f"new_shards ({growth.new_shards}) must "
+                                 f"exceed the current mesh "
+                                 f"({engine.n_shards})")
+        if jnl is not None and checkpoint_dir is not None:
+            snapshot.save(checkpoint_dir, _mem_state(st, jnl),
+                          extra={"round": -1})
+        programs = _sub_rounds(cfg, lay, oracle, engine, stock_last_n)
+
+    def host(x):
+        """Every blocking device-to-host read of the round loop and of the
+        closing retry count goes through here: one ``tpcc.read`` span and
+        one count each."""
+        nonlocal host_reads
+        with _span("tpcc.read"):
+            host_reads += 1
+            return jax.device_get(x)
 
     def acc_ops(name, ops):
         for i, f in enumerate(ops):
-            ops_sum[name][i] += float(f)
+            ops_sum[name][i] += float(host(f))
 
     def acc_local(w_id, d_id, slots, mask):
         nonlocal lf_local, lf_total
@@ -1490,143 +1517,141 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
             return
         srv = locality.route_transactions(
             locality_mode, placement, d_slot(lay, w_id, d_id), tids, T)
-        n_acc = float(jnp.sum(mask))
-        lf_local += float(locality.local_fraction(
-            placement, srv, slots, mask)) * n_acc
+        n_acc = float(host(jnp.sum(mask)))
+        lf_local += float(host(locality.local_fraction(
+            placement, srv, slots, mask))) * n_acc
         lf_total += n_acc
 
     def acc_write(name, act, committed, ops, snap_miss, vis):
-        attempts[name] += int(jnp.sum(act))
-        commits[name] += int(jnp.sum(committed))
+        attempts[name] += int(host(jnp.sum(act)))
+        commits[name] += int(host(jnp.sum(committed)))
         aborted = act & ~committed
-        n_ab = int(jnp.sum(aborted))
+        n_ab = int(host(jnp.sum(aborted)))
         retries[name] += n_ab
-        n_miss = int(jnp.sum(snap_miss & act))
+        n_miss = int(host(jnp.sum(snap_miss & act)))
         snapshot_misses[name] += n_miss
         contention_aborts[name] += n_ab - n_miss
-        ovf_reads[name] += int(vis.n_ovf)
+        ovf_reads[name] += int(host(vis.n_ovf))
         acc_ops(name, ops)
         return aborted
 
+    def gate(ttype, name):
+        """The lanes that drew ``name`` this round, and how many. A type
+        that drew none is skipped outright: its masked sub-round would be a
+        pure no-op contributing zero stats."""
+        with _span("tpcc.gate"):
+            act = ttype == names.index(name)
+            return act, int(host(jnp.sum(act)))
+
     for r in range(n_rounds):
-        if failure is not None and r == failure.kill_round:
-            if failure.in_flight:
-                st, jnl = _inflight_intents(
-                    cfg, lay, st, jnl, key, pending, pending_type, r,
-                    home_w, dist_degree, logits, mix, skew=skew)
-            st, jnl, rep = recover_from_failure(
-                cfg, lay, st, engine, jnl, checkpoint_dir, failure,
-                use_gc=use_gc, move_versions=move_versions)
-            recovery.append(rep)
-        if growth is not None and r == growth.grow_round:
-            st, jnl, engine, gc_log, grep = scale_out(
-                cfg, lay, st, oracle, engine, jnl, checkpoint_dir, growth,
-                use_gc=use_gc, move_versions=move_versions, gc_log=gc_log)
-            placement = engine.placement
-            programs = _sub_rounds(cfg, lay, oracle, engine, stock_last_n)
-            growth_reports.append(grep)
-            # the retry queues ride across the cut untouched in content, but
-            # their arrays are committed to the old mesh — re-land them
-            pending_type = jnp.asarray(jax.device_get(pending_type))
-            if pending is not None:
-                pending = jax.tree.map(
-                    lambda x: jnp.asarray(jax.device_get(x)), pending)
-        key, sub = jax.random.split(key)
-        fresh = workload.gen_mixed(sub, T, cfg.n_warehouses, cfg.n_items,
-                                   cfg.customers_per_district, home_w,
-                                   dist_degree, logits, mix, skew=skew)
-        # a retried txn keeps its original type AND inputs (MixedInputs
-        # carries both, so one merge covers the per-type retry queues)
-        inp = _merge_retries(pending, fresh, pending_type >= 0, T)
-        ttype = inp.txn_type
-        aborted_round = jnp.zeros((T,), bool)
+        with _span("tpcc.round", round=r):
+            if failure is not None and r == failure.kill_round:
+                if failure.in_flight:
+                    st, jnl = _inflight_intents(
+                        cfg, lay, st, jnl, key, pending, pending_type, r,
+                        home_w, dist_degree, logits, mix, skew=skew)
+                st, jnl, rep = recover_from_failure(
+                    cfg, lay, st, engine, jnl, checkpoint_dir, failure,
+                    use_gc=use_gc, move_versions=move_versions)
+                recovery.append(rep)
+            if growth is not None and r == growth.grow_round:
+                st, jnl, engine, gc_log, grep = scale_out(
+                    cfg, lay, st, oracle, engine, jnl, checkpoint_dir,
+                    growth, use_gc=use_gc, move_versions=move_versions,
+                    gc_log=gc_log)
+                placement = engine.placement
+                programs = _sub_rounds(cfg, lay, oracle, engine,
+                                       stock_last_n)
+                growth_reports.append(grep)
+                # the retry queues ride across the cut untouched in
+                # content, but their arrays are committed to the old mesh
+                # — re-land them
+                pending_type = jnp.asarray(jax.device_get(pending_type))
+                if pending is not None:
+                    pending = jax.tree.map(
+                        lambda x: jnp.asarray(jax.device_get(x)), pending)
+            with _span("tpcc.draw"):
+                key, sub = jax.random.split(key)
+                fresh = workload.gen_mixed(
+                    sub, T, cfg.n_warehouses, cfg.n_items,
+                    cfg.customers_per_district, home_w, dist_degree, logits,
+                    mix, skew=skew)
+                # a retried txn keeps its original type AND inputs
+                # (MixedInputs carries both, so one merge covers the
+                # per-type retry queues)
+                inp = _merge_retries(pending, fresh, pending_type >= 0, T)
+                ttype = inp.txn_type
+                aborted_round = jnp.zeros((T,), bool)
 
-        # ---- write transactions, one type-homogeneous sub-round each -----
-        # (a type that drew zero lanes this round is skipped outright — the
-        # masked sub-round would be a pure no-op contributing zero stats)
-        act = ttype == 0
-        if int(jnp.sum(act)):
-            out = programs["neworder"](st, inp.neworder, r, act, jnl)
-            st, jnl = out.state, out.journal
-            aborted_round |= acc_write("neworder", act, out.committed,
-                                       out.ops, out.snapshot_miss, out.vis)
-            acc_local(inp.neworder.w_id, inp.neworder.d_id,
-                      out.batch.read_slots, out.batch.read_mask)
+            # ---- write transactions, one type-homogeneous sub-round each -
+            for name in ("neworder", "payment", "delivery"):
+                act, n_act = gate(ttype, name)
+                if not n_act:
+                    continue
+                sub_inp = getattr(inp, name)
+                out = programs[name](st, sub_inp, r, act, jnl)
+                st, jnl = out.state, out.journal
+                with _span("tpcc.tally"):
+                    aborted_round |= acc_write(name, act, out.committed,
+                                               out.ops, out.snapshot_miss,
+                                               out.vis)
+                    if name == "delivery":
+                        delivered += int(host(jnp.sum(out.delivered)))
+                    acc_local(sub_inp.w_id, sub_inp.d_id,
+                              out.batch.read_slots, out.batch.read_mask)
 
-        act = ttype == 1
-        if int(jnp.sum(act)):
-            pay = programs["payment"](st, inp.payment, r, act, jnl)
-            st, jnl = pay.state, pay.journal
-            aborted_round |= acc_write("payment", act, pay.committed,
-                                       pay.ops, pay.snapshot_miss, pay.vis)
-            acc_local(inp.payment.w_id, inp.payment.d_id,
-                      pay.batch.read_slots, pay.batch.read_mask)
+            # ---- read-only transactions: snapshot reads, never abort -----
+            for name in READ_ONLY_TYPES:
+                act, n_act = gate(ttype, name)
+                if not n_act:
+                    continue
+                sub_inp = getattr(inp, name)
+                ro = programs[name](st, sub_inp, act)
+                with _span("tpcc.tally"):
+                    attempts[name] += n_act
+                    commits[name] += n_act
+                    acc_ops(name, ro.ops)
+                    acc_local(sub_inp.w_id, sub_inp.d_id, ro.read_slots,
+                              ro.read_mask)
 
-        act = ttype == 3
-        if int(jnp.sum(act)):
-            dl = programs["delivery"](st, inp.delivery, r, act, jnl)
-            st, jnl = dl.state, dl.journal
-            aborted_round |= acc_write("delivery", act, dl.committed, dl.ops,
-                                       dl.snapshot_miss, dl.vis)
-            delivered += int(jnp.sum(dl.delivered))
-            acc_local(inp.delivery.w_id, inp.delivery.d_id,
-                      dl.batch.read_slots, dl.batch.read_mask)
+            with _span("tpcc.tally"):
+                pending_type = jnp.where(aborted_round, ttype, -1)
+                pending = inp
+            if move_versions:
+                st = st._replace(nam=st.nam._replace(
+                    table=_version_mover(st.nam.table, reuse_only=use_gc)))
+            if use_gc and (r + 1) % gc_interval == 0:
+                st, gc_log, frac = _gc_sweep(lay, st, engine, gc_log, r,
+                                             max_txn_time)
+                gc_sweeps += 1
+                reclaim_traj.append((r, frac))
+                if jnl is not None and checkpoint_dir is not None:
+                    # checkpoint at every GC sweep: replay from the last
+                    # checkpoint then never spans a GC truncation, so the
+                    # journal alone reconstructs the lost shard bit-exactly
+                    snapshot.save(checkpoint_dir, _mem_state(st, jnl),
+                                  extra={"round": r})
+            with _span("tpcc.tally"):
+                ovf_peak = max(ovf_peak, int(host(
+                    jnp.max(st.nam.table.ovf_next))))
 
-        # ---- read-only transactions: snapshot reads, never abort ---------
-        act = ttype == 2
-        n_act = int(jnp.sum(act))
-        if n_act:
-            ro = programs["orderstatus"](st, inp.orderstatus, act)
-            attempts["orderstatus"] += n_act
-            commits["orderstatus"] += n_act
-            acc_ops("orderstatus", ro.ops)
-            acc_local(inp.orderstatus.w_id, inp.orderstatus.d_id,
-                      ro.read_slots, ro.read_mask)
-
-        act = ttype == 4
-        n_act = int(jnp.sum(act))
-        if n_act:
-            sl = programs["stocklevel"](st, inp.stocklevel, act)
-            attempts["stocklevel"] += n_act
-            commits["stocklevel"] += n_act
-            acc_ops("stocklevel", sl.ops)
-            acc_local(inp.stocklevel.w_id, inp.stocklevel.d_id,
-                      sl.read_slots, sl.read_mask)
-
-        pending_type = jnp.where(aborted_round, ttype, -1)
-        pending = inp
-        if move_versions:
-            st = st._replace(nam=st.nam._replace(
-                table=_version_mover(st.nam.table, reuse_only=use_gc)))
-        if use_gc and (r + 1) % gc_interval == 0:
-            st, gc_log, frac = _gc_sweep(lay, st, engine, gc_log, r,
-                                         max_txn_time)
-            gc_sweeps += 1
-            reclaim_traj.append((r, frac))
-            if jnl is not None and checkpoint_dir is not None:
-                # checkpoint at every GC sweep: replay from the last
-                # checkpoint then never spans a GC truncation, so the
-                # journal alone reconstructs the lost shard bit-exactly
-                snapshot.save(checkpoint_dir, _mem_state(st, jnl),
-                              extra={"round": r})
-        ovf_peak = max(ovf_peak, int(jnp.max(st.nam.table.ovf_next)))
-
-    # the last round's aborts never re-entered a later round
-    for i, n in enumerate(names):
-        retries[n] -= int(jnp.sum(pending_type == i))
-    total_attempts = sum(attempts.values())
-    total_commits = sum(commits.values())
-    stats = MixedRunStats(
-        attempts=attempts, commits=commits, retries=retries,
-        ops={n: si.OpCounts(*ops_sum[n]) for n in names},
-        total_attempts=total_attempts, total_commits=total_commits,
-        abort_rate=1.0 - total_commits / max(1, total_attempts),
-        local_fraction=lf_local / lf_total if lf_total else float("nan"),
-        delivered=delivered, snapshot_misses=snapshot_misses,
-        contention_aborts=contention_aborts, ovf_reads=ovf_reads,
-        gc_sweeps=gc_sweeps, reclaim_traj=tuple(reclaim_traj),
-        ovf_peak=ovf_peak, recovery=tuple(recovery),
-        growth=tuple(growth_reports))
+    with _span("tpcc.finish"):
+        # the last round's aborts never re-entered a later round
+        for i, n in enumerate(names):
+            retries[n] -= int(host(jnp.sum(pending_type == i)))
+        total_attempts = sum(attempts.values())
+        total_commits = sum(commits.values())
+        stats = MixedRunStats(
+            attempts=attempts, commits=commits, retries=retries,
+            ops={n: si.OpCounts(*ops_sum[n]) for n in names},
+            total_attempts=total_attempts, total_commits=total_commits,
+            abort_rate=1.0 - total_commits / max(1, total_attempts),
+            local_fraction=lf_local / lf_total if lf_total else float("nan"),
+            delivered=delivered, snapshot_misses=snapshot_misses,
+            contention_aborts=contention_aborts, ovf_reads=ovf_reads,
+            gc_sweeps=gc_sweeps, reclaim_traj=tuple(reclaim_traj),
+            ovf_peak=ovf_peak, recovery=tuple(recovery),
+            growth=tuple(growth_reports), host_reads=host_reads)
     return st, stats
 
 
@@ -1827,6 +1852,7 @@ class ReadOnlyRoundResult(NamedTuple):
     read_mask: jnp.ndarray
 
 
+@jax.named_scope("si.read")
 def _snapshot_read(st: TPCCState, engine, vec, slots, mask, keys=None,
                    key_mask=None):
     """Visible reads of ``slots`` [T, A] — through the sharded pool when an
