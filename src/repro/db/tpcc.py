@@ -1315,7 +1315,10 @@ def _sub_rounds(cfg: TPCCConfig, lay: TPCCLayout, oracle: VectorOracle,
     round after its first, because the retry merge then hands in inputs
     replicated over the mesh in place of the first round's single-device
     draws. Each program carries its type's round function name, which is
-    the name JAX's compile events report."""
+    the name JAX's compile events report. The read-only programs run their
+    round function on the active lanes alone, a chunk at a time
+    (:func:`_over_active_lanes`): their cost follows the lanes that drew
+    them, not ``T``."""
     def jit_as(fn, name):
         fn.__name__ = fn.__qualname__ = name
         return jax.jit(fn)
@@ -1333,12 +1336,17 @@ def _sub_rounds(cfg: TPCCConfig, lay: TPCCLayout, oracle: VectorOracle,
         return jit_as(run, single.__name__)
 
     def orderstatus(st, inp, active):
-        return orderstatus_round(cfg, lay, st, oracle, inp, engine=engine,
-                                 active=active)
+        return _over_active_lanes(
+            lambda sub, act: orderstatus_round(
+                cfg, lay, st, oracle, sub, engine=engine, active=act),
+            inp, active)
 
     def stocklevel(st, inp, active):
-        return stocklevel_round(cfg, lay, st, oracle, inp, engine=engine,
-                                active=active, last_n=last_n)
+        return _over_active_lanes(
+            lambda sub, act: stocklevel_round(
+                cfg, lay, st, oracle, sub, engine=engine, active=act,
+                last_n=last_n),
+            inp, active)
 
     return {"neworder": write(neworder_round, neworder_round_distributed),
             "payment": write(payment_round, payment_round_distributed),
@@ -1382,6 +1390,10 @@ class MixedRunStats(NamedTuple):
     #                                 mesh expansion (DESIGN.md §4.3)
     host_reads: int = 0             # blocking device-to-host reads of the
     #                                 round loop and the closing retry count
+    readonly_chunks: dict = None    # read-only type -> lane chunks its
+    #                                 program ran (readonly_lane_chunk lanes
+    #                                 each); attempts / (chunks x lanes) is
+    #                                 the chunks' fill
 
 
 def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
@@ -1463,6 +1475,8 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
         use_gc = gc_interval > 0
         gc_log = _gc_init(oracle, engine, gc_interval, gc_snapshots)
         gc_sweeps = ovf_peak = host_reads = 0
+        chunk_lanes = readonly_lane_chunk(T)
+        readonly_chunks = {n: 0 for n in READ_ONLY_TYPES}
         reclaim_traj = []
         delivered = 0
         lf_local = lf_total = 0.0
@@ -1610,6 +1624,7 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
                 with _span("tpcc.tally"):
                     attempts[name] += n_act
                     commits[name] += n_act
+                    readonly_chunks[name] += -(-n_act // chunk_lanes)
                     acc_ops(name, ro.ops)
                     acc_local(sub_inp.w_id, sub_inp.d_id, ro.read_slots,
                               ro.read_mask)
@@ -1651,7 +1666,8 @@ def run_mixed_rounds(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,
             contention_aborts=contention_aborts, ovf_reads=ovf_reads,
             gc_sweeps=gc_sweeps, reclaim_traj=tuple(reclaim_traj),
             ovf_peak=ovf_peak, recovery=tuple(recovery),
-            growth=tuple(growth_reports), host_reads=host_reads)
+            growth=tuple(growth_reports), host_reads=host_reads,
+            readonly_chunks=readonly_chunks)
     return st, stats
 
 
@@ -1886,6 +1902,59 @@ def _snapshot_read(st: TPCCState, engine, vec, slots, mask, keys=None,
     W = st.nam.table.payload_width
     return (vr.data.reshape(T, A, W), (vr.found & key_ok).reshape(T, A),
             (vr.from_current & key_ok).reshape(T, A))
+
+
+def readonly_lane_chunk(T: int) -> int:
+    """Lanes a read-only program runs at once out of ``T``: the smallest
+    power of two at least ``T / 32``. A round's read-only lanes are a few
+    percent of its fresh draws, so a steady round fits one chunk."""
+    k = 1
+    while 32 * k < T:
+        k *= 2
+    return k
+
+
+def _over_active_lanes(body, inp, active) -> ReadOnlyRoundResult:
+    """Run the read-only ``body(inp, active)`` on the active lanes alone.
+
+    The lanes whose ``active`` is set are gathered, in lane order, into
+    chunks of :func:`readonly_lane_chunk` lanes; ``body`` runs on one chunk
+    at a time inside a device loop over as many chunks as there are active
+    lanes, and its answers land back on their lanes. The outputs have the
+    full-width shapes: active lanes carry the values the masked body over
+    all ``T`` lanes gives, inactive lanes are zero (``found`` and
+    ``read_mask`` false), and ``ops`` sums the chunks'. Each lane's answer
+    depends on its own inputs alone, so the result is exact at every
+    active count, and the shapes never depend on it."""
+    T = active.shape[0]
+    K = readonly_lane_chunk(T)
+    ids = jnp.nonzero(active, size=-(-T // K) * K, fill_value=T)[0]
+    n_chunks = (jnp.sum(active.astype(jnp.int32)) + K - 1) // K
+
+    def chunk(c):
+        lanes = jax.lax.dynamic_slice(ids, (c * K,), (K,))
+        sub = jax.tree.map(lambda x: x[jnp.minimum(lanes, T - 1)], inp)
+        return lanes, body(sub, lanes < T)
+
+    def per_lane(o):
+        return o.result, o.found, o.read_slots, o.read_mask
+
+    def step(carry):
+        c, answers, ops = carry
+        lanes, out = chunk(c)
+        answers = tuple(a.at[lanes].set(v, mode="drop")
+                        for a, v in zip(answers, per_lane(out)))
+        ops = jax.tree.map(jnp.add, ops, out.ops)
+        return c + 1, answers, ops
+
+    shapes = jax.eval_shape(lambda: chunk(jnp.int32(0))[1])
+    answers = tuple(jnp.zeros((T,) + s.shape[1:], s.dtype)
+                    for s in per_lane(shapes))
+    ops = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes.ops)
+    _, (result, found, slots, mask), ops = jax.lax.while_loop(
+        lambda carry: carry[0] < n_chunks, step, (jnp.int32(0), answers, ops))
+    return ReadOnlyRoundResult(result=result, found=found, ops=ops,
+                               read_slots=slots, read_mask=mask)
 
 
 def orderstatus_round(cfg: TPCCConfig, lay: TPCCLayout, st: TPCCState,

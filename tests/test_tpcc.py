@@ -1,4 +1,8 @@
 """TPC-C integration tests: consistency invariants the benchmark defines."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +11,8 @@ import pytest
 from repro.core import header as hdr, mvcc
 from repro.core.tsoracle import VectorOracle
 from repro.db import tpcc, workload
+
+import _readonly_common as roc
 
 
 CFG = tpcc.TPCCConfig(n_warehouses=2, customers_per_district=8, n_items=64,
@@ -395,3 +401,110 @@ def test_key_addressed_directory_miss_aborts():
     out = tpcc.neworder_round(cfg, lay, st, oracle, inp)
     assert not bool(np.asarray(out.committed).any())
     assert bool(np.asarray(out.snapshot_miss).all())
+
+
+# ------------------------------------- read-only programs over their lanes --
+@pytest.fixture(scope="module")
+def ro_loaded():
+    oracle = VectorOracle(roc.CFG.n_threads)
+    lay, st = roc.loaded(oracle)
+    return oracle, lay, st, tpcc._sub_rounds(roc.CFG, lay, oracle, None,
+                                             roc.LAST_N)
+
+
+@pytest.mark.parametrize("name", roc.NAMES)
+@pytest.mark.parametrize("n_active", roc.COUNTS)
+def test_readonly_program_equals_the_masked_full_width_round(ro_loaded, name,
+                                                             n_active):
+    """A read-only program runs its round function on the active lanes
+    alone, a chunk at a time: one chunk, two, and many. Its answers, read
+    sets and op counts equal the round function's over all lanes with the
+    mask; a stock-level count equals the query run for its lane alone."""
+    oracle, lay, st, programs = ro_loaded
+    inp, act = roc.inputs(name), roc.active(n_active)
+    got = programs[name](st, inp, jnp.asarray(act))
+    want = roc.full_width(name, lay, oracle)(st, inp, jnp.asarray(act))
+    roc.assert_same_answers(got, want, act)
+    if name != "stocklevel":
+        return
+    one = jax.jit(lambda st, w, d, th: tpcc.stocklevel(
+        roc.CFG, lay, st, oracle, w, d, th, last_n=roc.LAST_N))
+    counts = np.asarray(got.result)
+    for lane in np.flatnonzero(act):
+        assert int(one(st, inp.w_id[lane], inp.d_id[lane],
+                       inp.threshold[lane])) == counts[lane], lane
+
+
+def test_readonly_program_answers_are_not_vacuous(ro_loaded):
+    """The store the comparisons above read gives both kinds of answer:
+    districts with an order and without, counts of zero and above."""
+    oracle, lay, st, programs = ro_loaded
+    T = roc.CFG.n_threads
+    act = jnp.ones(T, bool)
+    sl = programs["stocklevel"](st, roc.inputs("stocklevel"), act)
+    os_ = programs["orderstatus"](st, roc.inputs("orderstatus"), act)
+    assert T // 16 <= int(jnp.sum(sl.result > 0)) < T
+    assert T // 16 <= int(jnp.sum(os_.found)) < T
+
+
+def test_readonly_program_on_a_mesh_equals_the_full_width_round():
+    """The same comparison through the mesh's read executor on four
+    devices, in a process of its own (the host device count is fixed
+    before JAX starts)."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": os.pathsep.join(
+               [os.path.join(os.path.dirname(here), "src"), here]
+               + [p for p in [os.environ.get("PYTHONPATH")] if p])}
+    out = subprocess.run(
+        [sys.executable, os.path.join(here, "_readonly_chunks_check.py")],
+        capture_output=True, text=True, timeout=600, env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "READONLY_CHUNKS_OK" in out.stdout
+
+
+def test_readonly_chunks_counter(monkeypatch):
+    """``readonly_chunks`` counts ceil(lanes / chunk) over the rounds a
+    read-only type was drawn in, from the gate's count: the blocking reads
+    stay those of the 12/13/7/1 arithmetic."""
+    cfg = roc.CFG
+    oracle = VectorOracle(cfg.n_threads)
+    lay, st = tpcc.init_tpcc(cfg, oracle, jax.random.PRNGKey(3))
+    drawn = {n: [] for n in workload.TXN_TYPES}
+    real = tpcc._sub_rounds
+
+    def counted(*a):
+        def wrap(name, prog):
+            def call(st, inp, *rest):
+                act = rest[0] if name in tpcc.READ_ONLY_TYPES else rest[1]
+                drawn[name].append(int(jnp.sum(act)))
+                return prog(st, inp, *rest)
+            return call
+        return {k: wrap(k, v) for k, v in real(*a).items()}
+
+    monkeypatch.setattr(tpcc, "_sub_rounds", counted)
+    n = 3
+    _, stats = tpcc.run_mixed_rounds(
+        cfg, lay, st, oracle, jax.random.PRNGKey(4), n,
+        mix={"neworder": 0.3, "payment": 0.3, "orderstatus": 0.13,
+             "delivery": 0.14, "stocklevel": 0.13},
+        stock_last_n=roc.LAST_N)
+    K = tpcc.readonly_lane_chunk(cfg.n_threads)
+    for name in tpcc.READ_ONLY_TYPES:
+        assert stats.attempts[name] == sum(drawn[name])
+        assert stats.readonly_chunks[name] == sum(-(-a // K)
+                                                  for a in drawn[name])
+        assert stats.readonly_chunks[name] > len(drawn[name])   # > 1 a call
+    calls = {k: len(v) for k, v in drawn.items()}
+    writes = calls["neworder"] + calls["payment"] + calls["delivery"]
+    reads = calls["orderstatus"] + calls["stocklevel"]
+    assert stats.host_reads == (6 * n + 11 * writes + calls["delivery"]
+                                + 6 * reads + 5)
+
+
+def test_readonly_lane_chunk():
+    """The smallest power of two at least T / 32."""
+    assert [tpcc.readonly_lane_chunk(t) for t in (1, 8, 32, 33, 128, 400,
+                                                  1600)] == \
+        [1, 1, 1, 2, 4, 16, 64]
